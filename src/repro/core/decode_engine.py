@@ -339,6 +339,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.cache_manager import (CachePlan, StateSlotPool,
                                       capture_dense_state,
                                       restore_dense_state)
@@ -626,6 +627,10 @@ class DecodeEngine:
         self._seg_key = None        # composition signature of cached metadata
         self._seg_dev = None        # device-uploaded (perm, inv, blocks)
         self.steps = 0              # decode steps executed (all slots)
+        # sum over chunks of live streams x steps: live_slot_steps /
+        # (steps x num_slots) is the share of slot-steps that decoded a
+        # stream rather than an empty slot
+        self.live_slot_steps = 0
         self.last_chunk_s = 0.0
         # failure-semantics state (module docstring, failure section)
         self.rejected: list[_PendingJoin] = []   # terminally rejected joins
@@ -959,20 +964,21 @@ class DecodeEngine:
         so syncing never retraces."""
         if not self._ptab_dirty:
             return
-        self._spec_cols = []
-        for sub in self.pool:
-            if isinstance(sub, dict) and "page_table" in sub:
-                nper = sub["page_table"].shape[0]
-                full = np.broadcast_to(self._ptab[None],
-                                       (nper,) + self._ptab.shape)
-                sub["page_table"] = jnp.asarray(
-                    full[..., :self._plain_pages])
-                # speculative headroom columns (empty at spec_k=0); the
-                # spec dispatch concatenates these behind the plain-width
-                # table in-graph — see the ctor's parity note
-                self._spec_cols.append(
-                    jnp.asarray(full[..., self._plain_pages:]))
-        self._ptab_dirty = False
+        with tracing.span("fmplex.page_table_sync"):
+            self._spec_cols = []
+            for sub in self.pool:
+                if isinstance(sub, dict) and "page_table" in sub:
+                    nper = sub["page_table"].shape[0]
+                    full = np.broadcast_to(self._ptab[None],
+                                           (nper,) + self._ptab.shape)
+                    sub["page_table"] = jnp.asarray(
+                        full[..., :self._plain_pages])
+                    # speculative headroom columns (empty at spec_k=0); the
+                    # spec dispatch concatenates these behind the plain-
+                    # width table in-graph — see the ctor's parity note
+                    self._spec_cols.append(
+                        jnp.asarray(full[..., self._plain_pages:]))
+            self._ptab_dirty = False
 
     # ---- host-RAM spill tier (paged layout) ----
     def _paged_subs(self):
@@ -985,7 +991,7 @@ class DecodeEngine:
         padded to the fixed width with the trash page, so the gather
         compiles exactly once — spill traffic never retraces."""
         if self._jit_gather is None:
-            def gather(pool, page_idx, slot):
+            def spill_gather(pool, page_idx, slot):
                 out = []
                 for sub in pool:
                     if not (isinstance(sub, dict) and "page_table" in sub):
@@ -1001,7 +1007,7 @@ class DecodeEngine:
                         "v_max": sub["v_max"][:, slot],
                     })
                 return out
-            self._jit_gather = jax.jit(gather)
+            self._jit_gather = jax.jit(spill_gather)
         return self._jit_gather
 
     def _page_restore_fn(self):
@@ -1010,7 +1016,7 @@ class DecodeEngine:
         content is garbage by contract), so duplicate trash writes from the
         padding are harmless and the write compiles exactly once."""
         if self._jit_page_restore is None:
-            def write(pool, data, page_idx):
+            def page_restore(pool, data, page_idx):
                 out, i = [], 0
                 for sub in pool:
                     if not (isinstance(sub, dict) and "page_table" in sub):
@@ -1027,14 +1033,14 @@ class DecodeEngine:
                     out.append(d)
                 return out
 
-            self._jit_page_restore = jax.jit(write, donate_argnums=0)
+            self._jit_page_restore = jax.jit(page_restore, donate_argnums=0)
         return self._jit_page_restore
 
     def _slot_restore_fn(self):
         """H2D write-back of one slot's running scales, drift trackers and
         true length — the second half of a spill resume."""
         if self._jit_slot_restore is None:
-            def write(pool, state, slot, true_len):
+            def slot_restore(pool, state, slot, true_len):
                 out, i = [], 0
                 for sub in pool:
                     if not (isinstance(sub, dict) and "page_table" in sub):
@@ -1052,7 +1058,7 @@ class DecodeEngine:
                     out.append(d)
                 return out
 
-            self._jit_slot_restore = jax.jit(write, donate_argnums=0)
+            self._jit_slot_restore = jax.jit(slot_restore, donate_argnums=0)
         return self._jit_slot_restore
 
     def _capture_pages(self, pages: np.ndarray, slot: int) -> list:
@@ -1250,8 +1256,9 @@ class DecodeEngine:
             enc_len = self.enc_len
 
             @jax.jit
-            def run(params, tokens, true_len, enc_embeds, rng_key, lora_stack,
-                    adapter_idx, perm, inv, blocks):
+            def admission_prefill(params, tokens, true_len, enc_embeds,
+                                  rng_key, lora_stack, adapter_idx, perm, inv,
+                                  blocks):
                 seg = None
                 if impl == "segmented":
                     seg = {"perm": perm, "inv": inv, "block_adapter": blocks,
@@ -1268,7 +1275,7 @@ class DecodeEngine:
                 # any page allocation or prefix registration
                 return first, lm.finite_logits(logits), rng_key, cache
 
-            self._jit_prefill[key] = run
+            self._jit_prefill[key] = admission_prefill
         return self._jit_prefill[key]
 
     def _tail_prefill_fn(self, cap: int, tlen: int, mode: str = "float"):
@@ -1326,19 +1333,22 @@ class DecodeEngine:
 
             if mode == "float":
                 @jax.jit
-                def run(params, prefix_fp, tokens, tail_len, prefix_len,
-                        rng_key, lora_stack, adapter_idx, perm, inv, blocks):
+                def tail_prefill_float(params, prefix_fp, tokens, tail_len,
+                                       prefix_len, rng_key, lora_stack,
+                                       adapter_idx, perm, inv, blocks):
                     it = iter(prefix_fp)
                     prefix = [next(it) if paged else None
                               for paged in paged_mask]
                     return body(params, prefix, tokens, tail_len, prefix_len,
                                 rng_key, lora_stack, adapter_idx, perm, inv,
                                 blocks)
+                run = tail_prefill_float
             else:
                 @jax.jit
-                def run(params, pool, tokens, tail_len, prefix_pages,
-                        prefix_len, rng_key, lora_stack, adapter_idx, perm,
-                        inv, blocks):
+                def tail_prefill_pages(params, pool, tokens, tail_len,
+                                       prefix_pages, prefix_len, rng_key,
+                                       lora_stack, adapter_idx, perm, inv,
+                                       blocks):
                     # dequantized prefix K/V per attention sublayer, gathered
                     # from the arena through the explicit prefix page vector
                     # (positions past prefix_len point at the trash page and
@@ -1357,6 +1367,7 @@ class DecodeEngine:
                     return body(params, prefix, tokens, tail_len, prefix_len,
                                 rng_key, lora_stack, adapter_idx, perm, inv,
                                 blocks)
+                run = tail_prefill_pages
 
             self._jit_prefill[key] = run
         return self._jit_prefill[key]
@@ -1365,14 +1376,14 @@ class DecodeEngine:
         """Dense admission scatter: one dynamic_update_slice per cache leaf
         along the slot (batch) axis."""
         if None not in self._jit_write:
-            def write(pool, cache, slot):
+            def slot_write(pool, cache, slot):
                 # every cache leaf is (nper, batch, ...): scatter the one-row
                 # prefill cache into the pool's slot along the batch axis
                 return jax.tree.map(
                     lambda p, c: jax.lax.dynamic_update_slice_in_dim(
                         p, c.astype(p.dtype), slot, axis=1), pool, cache)
 
-            self._jit_write[None] = jax.jit(write, donate_argnums=0)
+            self._jit_write[None] = jax.jit(slot_write, donate_argnums=0)
         return self._jit_write[None]
 
     def _paged_write_fn(self, npages: int):
@@ -1391,7 +1402,7 @@ class DecodeEngine:
         if npages not in self._jit_write:
             ps = self.page_size
 
-            def write(pool, cache, slot, page_idx, true_len):
+            def page_write(pool, cache, slot, page_idx, true_len):
                 out = []
                 for psub, csub in zip(pool, cache):
                     if not (isinstance(psub, dict) and "page_table" in psub):
@@ -1460,7 +1471,7 @@ class DecodeEngine:
                     out.append(d)
                 return out
 
-            self._jit_write[npages] = jax.jit(write, donate_argnums=0)
+            self._jit_write[npages] = jax.jit(page_write, donate_argnums=0)
         return self._jit_write[npages]
 
     def _paged_tail_write_fn(self, npages: int):
@@ -1482,8 +1493,8 @@ class DecodeEngine:
         if key not in self._jit_write:
             ps = self.page_size
 
-            def write(pool, cache, slot, page_idx, true_len, boundary,
-                      prefix_pages, prefix_np):
+            def tail_page_write(pool, cache, slot, page_idx, true_len,
+                                boundary, prefix_pages, prefix_np):
                 out = []
                 W = prefix_pages.shape[0]
                 pmask = (jnp.arange(W) < prefix_np)[None, :, None]
@@ -1532,7 +1543,7 @@ class DecodeEngine:
                     out.append(d)
                 return out
 
-            self._jit_write[key] = jax.jit(write, donate_argnums=0)
+            self._jit_write[key] = jax.jit(tail_page_write, donate_argnums=0)
         return self._jit_write[key]
 
     def _rescale_fn(self):
@@ -1614,8 +1625,8 @@ class DecodeEngine:
 
             sample = self._sample
 
-            def run(params, pool, tokens, keys, lora_stack, adapter_idx,
-                    perm, inv, blocks):
+            def decode_chunk(params, pool, tokens, keys, lora_stack,
+                             adapter_idx, perm, inv, blocks):
                 seg = None
                 if impl == "segmented":
                     seg = {"perm": perm, "inv": inv, "block_adapter": blocks,
@@ -1650,7 +1661,7 @@ class DecodeEngine:
                             drift = drift | jnp.any(o, axis=(0, 2))
                 return pool, tok, keys, out.T, drift, fin    # (slots, chunk)
 
-            self._jit_decode[key] = jax.jit(run, donate_argnums=1)
+            self._jit_decode[key] = jax.jit(decode_chunk, donate_argnums=1)
         return self._jit_decode[key]
 
     def _spec_decode_fn(self, cap: int, chunk: int):
@@ -1692,8 +1703,9 @@ class DecodeEngine:
             plain_w = self._plain_pages
             bidx = jnp.arange(nslots)
 
-            def run(params, pool, tokens, keys, hist, hlen, spec_cols,
-                    lora_stack, adapter_idx, perm, inv, blocks):
+            def spec_decode_chunk(params, pool, tokens, keys, hist, hlen,
+                                  spec_cols, lora_stack, adapter_idx, perm,
+                                  inv, blocks):
                 seg = None
                 if impl == "segmented":
                     seg = {"perm": perm, "inv": inv, "block_adapter": blocks,
@@ -1825,7 +1837,8 @@ class DecodeEngine:
                 return (pool, tok, keys, gs.transpose(1, 0, 2), ms.T, ps.T,
                         drift, fin)
 
-            self._jit_decode[key] = jax.jit(run, donate_argnums=1)
+            self._jit_decode[key] = jax.jit(spec_decode_chunk,
+                                            donate_argnums=1)
         return self._jit_decode[key]
 
     def _spec_history(self):
@@ -2016,6 +2029,10 @@ class DecodeEngine:
         return self._admit_now(req)
 
     def _admit_now(self, req: _PendingJoin) -> int:
+        with tracing.span("fmplex.admit", rid=req.rid) as sp:
+            return self._admit(req, sp)
+
+    def _admit(self, req: _PendingJoin, sp) -> int:
         if req.resume is not None and self.paged and self.spill is not None:
             slot = self._try_spill_resume(req)
             if slot is not None:
@@ -2035,6 +2052,7 @@ class DecodeEngine:
         # right-pads out of its K/V and the recurrent scans carry state
         # through them unchanged, so the bucket is the only jit key
         true_len = max(1, len(prompt))
+        sp.set(prompt=true_len)
         plen = self.bucket_for_prompt(true_len)
         if len(prompt) < plen:                     # right-pad to the bucket
             prompt = np.concatenate(
@@ -2837,6 +2855,10 @@ class DecodeEngine:
         runs first: expired live slots are marked done (and retire below),
         expired pending entries are terminally rejected — so a wedge of
         deadline-carrying strandeds clears itself instead of raising."""
+        with tracing.span("fmplex.decode_chunk", live=0, steps=0) as sp:
+            return self._step_chunk(sp)
+
+    def _step_chunk(self, sp) -> list[DecodeSlot]:
         t0 = time.perf_counter()
         self._expire_deadlines(t0)
         if self.paged:
@@ -2848,7 +2870,8 @@ class DecodeEngine:
         live = [i for i, s in enumerate(self.slots)
                 if s is not None and not s.done]
         if live and self.paged:
-            self._ensure_chunk_pages()
+            with tracing.span("fmplex.page_topup"):
+                self._ensure_chunk_pages()
             # preemption may have evicted members of the live set
             live = [i for i, s in enumerate(self.slots)
                     if s is not None and not s.done]
@@ -2871,10 +2894,11 @@ class DecodeEngine:
                         hist, hlen, self._spec_cols,
                         self.fm.adapters.stacked(),
                         jnp.asarray(self._slot_adapters), perm, inv, blocks)
-                out_g = np.asarray(out_g)       # (slots, eff, k+1): one sync
-                out_m = np.asarray(out_m)       # (slots, eff) commit counts
-                out_p = np.asarray(out_p)       # (slots, eff) proposals
-                fin = np.asarray(fin)
+                with tracing.span("fmplex.chunk_sync"):
+                    out_g = np.asarray(out_g)   # (slots, eff, k+1): one sync
+                    out_m = np.asarray(out_m)   # (slots, eff) commit counts
+                    out_p = np.asarray(out_p)   # (slots, eff) proposals
+                    fin = np.asarray(fin)
             else:
                 perm, inv, blocks = self._segments(cap)
                 self.pool, self._tokens, self._keys, out, drift, fin = \
@@ -2882,8 +2906,9 @@ class DecodeEngine:
                         self.fm.params, self.pool, self._tokens, self._keys,
                         self.fm.adapters.stacked(),
                         jnp.asarray(self._slot_adapters), perm, inv, blocks)
-                out = np.asarray(out)           # one host sync per chunk
-                fin = np.asarray(fin)           # rides the same sync
+                with tracing.span("fmplex.chunk_sync"):
+                    out = np.asarray(out)       # one host sync per chunk
+                    fin = np.asarray(fin)       # rides the same sync
             # per-SCAN-STEP cost: the deadline clamp reasons in scan steps
             # either way, and a speculative step's extra verify cost is
             # exactly what the EMA must learn for the ladder to clamp right
@@ -2891,61 +2916,66 @@ class DecodeEngine:
             self._step_ema = dt if self._step_ema == 0.0 \
                 else 0.5 * self._step_ema + 0.5 * dt
             self.steps += eff
-            if self.paged:
-                for i, s in enumerate(self.slots):
-                    if s is not None:
-                        self._lens[i] += int(out_m[i].sum()) if use_spec \
-                            else eff
-            now = time.perf_counter()
-            for i in live:
-                s = self.slots[i]
+            self.live_slot_steps += len(live) * eff
+            sp.set(live=len(live), steps=eff)
+            with tracing.span("fmplex.chunk_commit"):
+                if self.paged:
+                    for i, s in enumerate(self.slots):
+                        if s is not None:
+                            self._lens[i] += int(out_m[i].sum()) if use_spec \
+                                else eff
+                now = time.perf_counter()
+                for i in live:
+                    s = self.slots[i]
+                    if use_spec:
+                        committed = [int(t) for st in range(eff)
+                                     for t in out_g[i, st, :out_m[i, st]]]
+                        prop = int(out_p[i].sum())
+                        self.spec_commits += len(committed)
+                        self.draft_proposed += prop
+                        self.draft_accepted += len(committed) - eff
+                        ts = self._spec_task_stats.setdefault(
+                            s.task_id, [0, 0])
+                        ts[0] += prop
+                        ts[1] += len(committed) - eff
+                    else:
+                        committed = [int(t) for t in out[i]]
+                    # fair-share accounting charges tokens actually COMMITTED
+                    # for this stream (accepted + corrected), never a flat
+                    # chunk x active_slots — see take_decode_charges()
+                    self._decode_charges[(s.task_id, s.rid)] += len(committed)
+                    take = min(len(committed), s.max_new - len(s.tokens))
+                    for t in committed[:take]:
+                        s.tokens.append(t)
+                        if s.eos_id is not None and t == s.eos_id:
+                            break
+                    # quarantine check only for LIVE slots: a freed slot's
+                    # garbage row may legitimately go non-finite (stale scales)
+                    # and must not trip anything
+                    if not fin[i]:
+                        s.done = True
+                        s.status = "quarantined"
+                        self.quarantines += 1
+                        finished.append(i)
+                    elif len(s.tokens) >= s.max_new or (
+                            s.eos_id is not None and s.tokens[-1] == s.eos_id):
+                        s.done = True
+                        finished.append(i)
                 if use_spec:
-                    committed = [int(t) for st in range(eff)
-                                 for t in out_g[i, st, :out_m[i, st]]]
-                    prop = int(out_p[i].sum())
-                    self.spec_commits += len(committed)
-                    self.draft_proposed += prop
-                    self.draft_accepted += len(committed) - eff
-                    ts = self._spec_task_stats.setdefault(s.task_id, [0, 0])
-                    ts[0] += prop
-                    ts[1] += len(committed) - eff
-                else:
-                    committed = [int(t) for t in out[i]]
-                # fair-share accounting charges tokens actually COMMITTED
-                # for this stream (accepted + corrected), never a flat
-                # chunk x active_slots — see take_decode_charges()
-                self._decode_charges[(s.task_id, s.rid)] += len(committed)
-                take = min(len(committed), s.max_new - len(s.tokens))
-                for t in committed[:take]:
-                    s.tokens.append(t)
-                    if s.eos_id is not None and t == s.eos_id:
-                        break
-                # quarantine check only for LIVE slots: a freed slot's
-                # garbage row may legitimately go non-finite (stale scales)
-                # and must not trip anything
-                if not fin[i]:
-                    s.done = True
-                    s.status = "quarantined"
-                    self.quarantines += 1
-                    finished.append(i)
-                elif len(s.tokens) >= s.max_new or (
-                        s.eos_id is not None and s.tokens[-1] == s.eos_id):
-                    s.done = True
-                    finished.append(i)
-            if use_spec:
-                self.spec_dispatches += 1
-                rate = sum(int(out_m[i].sum()) for i in live) \
-                    / max(eff * len(live), 1)
-                self._spec_accept_ema = rate if self._spec_accept_ema == 0.0 \
-                    else 0.5 * self._spec_accept_ema + 0.5 * rate
-                if self._spec_probe:
-                    # dry probe (1.0 committed/slot-step == zero accepts)
-                    # -> back off; any acceptance -> restore the base
-                    # cadence and let the EMA drive re-promotion
-                    self._spec_probe_interval = min(
-                        self._spec_probe_interval * 2,
-                        self.spec_probe_every * 16) if rate <= 1.0 \
-                        else self.spec_probe_every
+                    self.spec_dispatches += 1
+                    rate = sum(int(out_m[i].sum()) for i in live) \
+                        / max(eff * len(live), 1)
+                    ema = self._spec_accept_ema
+                    self._spec_accept_ema = rate if ema == 0.0 \
+                        else 0.5 * ema + 0.5 * rate
+                    if self._spec_probe:
+                        # dry probe (1.0 committed/slot-step == zero accepts)
+                        # -> back off; any acceptance -> restore the base
+                        # cadence and let the EMA drive re-promotion
+                        self._spec_probe_interval = min(
+                            self._spec_probe_interval * 2,
+                            self.spec_probe_every * 16) if rate <= 1.0 \
+                            else self.spec_probe_every
             self._maybe_refresh_scales(np.asarray(drift))
         retired += [self.leave(i) for i in finished]
         self.last_chunk_s = time.perf_counter() - t0
@@ -2960,9 +2990,9 @@ class DecodeEngine:
         return out
 
     # ---- engine snapshot / restore (durability layer) ----
-    _COUNTERS = ("steps", "admissions", "deferrals", "preemptions",
-                 "prefix_hits", "shared_pages_mapped", "scale_refreshes",
-                 "hol_bypasses", "_hol_skips", "quarantines",
+    _COUNTERS = ("steps", "live_slot_steps", "admissions", "deferrals",
+                 "preemptions", "prefix_hits", "shared_pages_mapped",
+                 "scale_refreshes", "hol_bypasses", "_hol_skips", "quarantines",
                  "deadline_cancels", "deadline_sheds", "stranded_rejections",
                  "cancels", "spilled_pages", "restored_pages",
                  "digest_failures", "spill_resumes", "spill_prefix_hits",

@@ -51,6 +51,7 @@ import zlib
 import jax
 import numpy as np
 
+from repro.core import tracing
 from repro.core.physical import PhysicalFM
 from repro.core.request import Batch
 from repro.core.vfm import VFM
@@ -188,7 +189,10 @@ class Executor:
 
         # device first: one jitted executable per (task, head, bucket)
         try:
-            self._head_jit[tid] = jax.jit(head)
+            def task_head(feats):
+                return head(feats)
+
+            self._head_jit[tid] = jax.jit(task_head)
             y = np.asarray(self._run_device_head(tid, feats_dev, idxs))
             if matches(y):
                 self._head_mode[tid] = (head, "device")
@@ -263,20 +267,23 @@ class Executor:
         ``PendingBatch`` whose ``resolve()`` applies heads and syncs. JAX
         dispatch is asynchronous, so the device works through the backbone
         pass while the caller assembles the next batch."""
-        # adapter-sorted layout: concatenate sub-batches (one adapter each)
-        order, embeds, aidx = [], [], []
-        for adapter_id, reqs in batch.sub_batches:
-            ai = self.fm.adapters.index(adapter_id)
-            for r in reqs:
-                order.append(r)
-                x = r.payload
-                if x is None:
-                    x = np.zeros((self.fm.input_len, self.fm.cfg.d_model),
-                                 np.float32)
-                embeds.append(x)
-                aidx.append(ai)
-        feats_dev = self.fm.run_batch_device(np.stack(embeds),
-                                             np.asarray(aidx, np.int32))
+        with tracing.span("fmplex.pooled_dispatch") as sp:
+            # adapter-sorted layout: concatenate sub-batches (one adapter
+            # each)
+            order, embeds, aidx = [], [], []
+            for adapter_id, reqs in batch.sub_batches:
+                ai = self.fm.adapters.index(adapter_id)
+                for r in reqs:
+                    order.append(r)
+                    x = r.payload
+                    if x is None:
+                        x = np.zeros((self.fm.input_len,
+                                      self.fm.cfg.d_model), np.float32)
+                    embeds.append(x)
+                    aidx.append(ai)
+            feats_dev = self.fm.run_batch_device(np.stack(embeds),
+                                                 np.asarray(aidx, np.int32))
+            sp.set(rows=len(order))
         return PendingBatch(self, batch, order, feats_dev)
 
     def execute(self, batch: Batch, vfms: dict[str, VFM]) -> dict[int, object]:
@@ -292,6 +299,11 @@ class Executor:
     def _finish(self, order, feats_dev) -> dict[int, object]:
         """Deferred half of ``execute_async``: per-task heads over the device
         features + host sync."""
+        with tracing.span("fmplex.pooled_resolve") as sp:
+            sp.set(rows=len(order))
+            return self._resolve(order, feats_dev)
+
+    def _resolve(self, order, feats_dev) -> dict[int, object]:
         # host copy, materialized lazily: only headless requests, probes, and
         # fallback-mode heads need it — all-device-head batches never pull
         feats_np: list = [None]

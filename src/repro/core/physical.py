@@ -233,7 +233,8 @@ class PhysicalFM:
             cfg, bt = self.cfg, self.seg_block_t
 
             @jax.jit
-            def run(params, embeds, lora_stack, adapter_idx, perm, inv, blocks):
+            def pooled_features(params, embeds, lora_stack, adapter_idx, perm,
+                                inv, blocks):
                 seg = None
                 if impl == "segmented":
                     seg = {"perm": perm, "inv": inv, "block_adapter": blocks,
@@ -253,7 +254,7 @@ class PhysicalFM:
                                              lora_impl=impl, lora_seg=seg)
                 return feats.mean(axis=1)                      # (B, d) pooled
 
-            self._jit_cache[key] = run
+            self._jit_cache[key] = pooled_features
         return self._jit_cache[key]
 
     def segment_meta(self, adapter_idx: np.ndarray, cap: int, seq_len: int):

@@ -96,6 +96,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.bfq import group_sub_batches
 from repro.core.request import Batch, Request
 
@@ -122,6 +123,10 @@ class ServeLoop:
         self._inflight: dict[int, Request] = {}  # rid -> loop-admitted request
         self.served: list[Request] = []
         self.ticks = collections.Counter()      # work-kind -> tick count
+        # ticks at which a generative request was queued and the admission
+        # gate said yes: an admission could have run, whatever ran instead
+        # (ticks["admit"] / admit_ready is how often it did)
+        self.admit_ready = 0
         self._tie_last = "decode"               # alternation state (see tick)
         self.page_samples: list[float] = []     # paged-pool occupancy / tick
         self.shared_samples: list[float] = []   # dedup fraction / decode tick
@@ -173,6 +178,12 @@ class ServeLoop:
     def tick(self, now: Optional[float] = None) -> str:
         """One scheduling decision: dispatch the smallest-tag unit of work.
         Returns the kind dispatched ('pooled' | 'admit' | 'decode' | 'idle')."""
+        with tracing.span("fmplex.tick") as sp:
+            kind = self._dispatch(now)
+            sp.set(kind=kind)
+        return kind
+
+    def _dispatch(self, now: Optional[float]) -> str:
         now = time.perf_counter() if now is None else now
         sched, vfms = self.sched, self._vfms()
         eng = self._engine()
@@ -218,6 +229,7 @@ class ServeLoop:
                 else:
                     admit_ok = eng.can_admit(1)
             if admit_ok:
+                self.admit_ready += 1
                 # ties: admit before pooled/decode — filling slots lets the
                 # next decode chunk amortize over more streams
                 candidates.append((gen_tag, -1, "admit"))
