@@ -189,8 +189,12 @@ def check_kernels(report: dict, *, interpret: bool = False) -> dict:
     for b in range(B):
         for j in range(-(-int(lens[b]) // ps)):
             pt[b, j] = free.pop()
-    got = paged_decode_attention(q, kp, vp, ks, vs, jnp.asarray(pt),
-                                 jnp.asarray(lens), interpret=interpret)
+    def stored(pages):        # oracle layout -> the arena as the pool keeps it
+        return pages.transpose(0, 2, 1, 3).reshape(P, ps, KV * hd)
+
+    got = paged_decode_attention(q, stored(kp), stored(vp), ks, vs,
+                                 jnp.asarray(pt), jnp.asarray(lens),
+                                 interpret=interpret)
     with jax.default_matmul_precision("highest"):
         want = ref.paged_decode_attention_ref(q, kp, vp, ks, vs,
                                               jnp.asarray(pt),

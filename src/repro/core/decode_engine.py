@@ -14,14 +14,15 @@ per-step cost of multi-task isolation must be ~zero. The engine owns:
         (``kernels.decode_attention_int8``). Every stream RESERVES its
         worst-case length, so the slot count — not memory — caps colocation.
       - *paged* (``paged=True``): one global arena of ``total_pages``
-        fixed-size pages (int8 K/V + per-(page, kv-head) scales,
-        ``page_size`` tokens each) shared by every slot, addressed through a
-        device-resident per-slot page table. Attention gathers K/V through
-        the page table inside the Pallas kernel grid
-        (``kernels.paged_decode_attention``; jnp gather oracle on CPU).
-        Page 0 is the reserved trash page: free slots keep stepping (static
-        shapes) and their garbage writes land there, never in a live
-        stream's pages.
+        fixed-size pages (int8 K/V rows of ``kv * head_dim`` + per-(page,
+        kv-head) scales, ``page_size`` tokens each) shared by every slot,
+        addressed through a device-resident per-slot page table. The Pallas
+        kernel DMAs a stream's whole pages through the table, only up to its
+        length (``kernels.paged_decode_attention``; jnp gather oracle on
+        CPU). Page 0 is the reserved trash page: free slots keep stepping
+        (static shapes) and their garbage writes land there, never in a live
+        stream's pages; the kernel reads a row whose first entry is page 0
+        as empty.
 
     **Cache-manager plane (``core.cache_manager``) — the cache contract is
     per-SUBLAYER, not per-engine.** ``CachePlan.for_config`` walks the
@@ -1450,8 +1451,11 @@ class DecodeEngine:
                         vf / jnp.maximum(vs, 1e-12)[:, :, None, :, None]),
                         -127, 127).astype(psub["v"].dtype)
                     d = dict(psub)
-                    d["k"] = psub["k"].at[:, page_idx].set(kq)
-                    d["v"] = psub["v"].at[:, page_idx].set(vq)
+                    # arena rows hold a token's KV heads side by side
+                    d["k"] = psub["k"].at[:, page_idx].set(
+                        kq.reshape(nper, npages, ps, kv * hd))
+                    d["v"] = psub["v"].at[:, page_idx].set(
+                        vq.reshape(nper, npages, ps, kv * hd))
                     d["k_scale"] = psub["k_scale"].at[:, page_idx].set(ks)
                     d["v_scale"] = psub["v_scale"].at[:, page_idx].set(vs)
                     d["slot_k_scale"] = psub["slot_k_scale"].at[:, slot].set(
@@ -1529,8 +1533,11 @@ class DecodeEngine:
                         vf / jnp.maximum(vs, 1e-12)[:, :, None, :, None]),
                         -127, 127).astype(psub["v"].dtype)
                     d = dict(psub)
-                    d["k"] = psub["k"].at[:, page_idx].set(kq)
-                    d["v"] = psub["v"].at[:, page_idx].set(vq)
+                    # arena rows hold a token's KV heads side by side
+                    d["k"] = psub["k"].at[:, page_idx].set(
+                        kq.reshape(nper, npages, ps, kv * hd))
+                    d["v"] = psub["v"].at[:, page_idx].set(
+                        vq.reshape(nper, npages, ps, kv * hd))
                     d["k_scale"] = psub["k_scale"].at[:, page_idx].set(ks)
                     d["v_scale"] = psub["v_scale"].at[:, page_idx].set(vs)
                     d["slot_k_scale"] = psub["slot_k_scale"].at[:, slot].set(
@@ -1571,15 +1578,20 @@ class DecodeEngine:
                                    old_ks / jnp.maximum(new_ks, 1e-12), 1.0)
                     rv = jnp.where(new_vs > 0,
                                    old_vs / jnp.maximum(new_vs, 1e-12), 1.0)
-                    kp = jnp.round(sub["k"][:, page].astype(jnp.float32)
-                                   * rk[:, None, :, None])
-                    vp = jnp.round(sub["v"][:, page].astype(jnp.float32)
-                                   * rv[:, None, :, None])
+                    nper, _, ps, w = sub["k"].shape
+                    kv = km.shape[1]
+
+                    def requant(pages, r):     # (nper, ps, kv*hd) codes
+                        x = pages.reshape(nper, ps, kv, w // kv)
+                        x = jnp.round(x.astype(jnp.float32)
+                                      * r[:, None, :, None])
+                        return x.reshape(nper, ps, w).astype(pages.dtype)
+
                     d = dict(sub)
                     d["k"] = sub["k"].at[:, page].set(
-                        kp.astype(sub["k"].dtype))
+                        requant(sub["k"][:, page], rk))
                     d["v"] = sub["v"].at[:, page].set(
-                        vp.astype(sub["v"].dtype))
+                        requant(sub["v"][:, page], rv))
                     d["k_scale"] = sub["k_scale"].at[:, page].set(new_ks)
                     d["v_scale"] = sub["v_scale"].at[:, page].set(new_vs)
                     d["slot_k_scale"] = sub["slot_k_scale"].at[:, slot].set(

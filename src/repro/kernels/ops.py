@@ -3,7 +3,11 @@
 ``backend="auto"`` uses the Pallas TPU kernels on TPU and the pure-jnp
 oracles elsewhere (CPU tests check the kernels in the Pallas interpreter and
 compile them for a described TPU). Layout adapters translate between the
-model-internal (B, S, H, hd) convention and the head-major kernel layout.
+model-internal (B, S, H, hd) convention and the head-major layout of the
+dense kernels. The paged pool's arena needs none: the paged kernel reads it
+as the pool stores it, ``(num_pages, page_size, KV * hd)`` int8 with the
+trash page 0 (see ``kernels.paged_decode_attention``), and the XLA paths
+gather pages from that layout first and reshape only the gathered block.
 """
 from __future__ import annotations
 
@@ -92,35 +96,38 @@ def paged_decode_attention(q, k_pages, v_pages, k_scale, v_scale, page_table,
                            lengths, *, window: Optional[int] = None,
                            backend: Optional[str] = None,
                            interpret: bool = False):
-    """Paged int8-KV decode attention, model layout.
+    """Paged int8-KV decode attention over the arena as the pool stores it.
 
-    q: (B, H, hd); k_pages/v_pages: (num_pages, ps, KV, hd) int8 arena;
+    q: (B, H, hd); k_pages/v_pages: (num_pages, ps, KV * hd) int8 arena;
     k_scale/v_scale: (num_pages, KV) per-page scales; page_table:
     (B, max_pages) int32 — rows of DIFFERENT streams may reference the same
     physical page (copy-on-write prefix sharing maps shared prompt pages
-    into several tables; the gather is read-only, so no kernel change);
-    lengths: (B,) -> (B, H, hd). The Pallas path gathers pages via the
-    scalar-prefetched table inside the kernel grid; the CPU oracle gathers
-    with jnp then reuses the f32 decode reference."""
+    into several tables; reads only, so no kernel change); lengths: (B,)
+    -> (B, H, hd).
+
+    The Pallas path hands the arena to the kernel untouched: it DMAs whole
+    pages through the scalar-prefetched table, only up to each stream's
+    length, and a row whose first entry is the trash page 0 (a free slot)
+    reads zeros. The CPU oracle gathers with jnp, whatever the rows hold,
+    then reuses the f32 decode reference."""
     b = _resolve(backend)
     if b == "pallas":
-        kh = k_pages.transpose(0, 2, 1, 3)      # (P, KV, ps, hd) head-major
-        vh = v_pages.transpose(0, 2, 1, 3)
-        return _paged_decode_pallas(q, kh, vh, k_scale, v_scale, page_table,
-                                    lengths, window=window,
+        return _paged_decode_pallas(q, k_pages, v_pages, k_scale, v_scale,
+                                    page_table, lengths, window=window,
                                     interpret=interpret)
-    # XLA path: gather from the model-layout arena FIRST (the gathered
-    # (B, MP, ps, KV, hd) block is per-request-sized), dequant with the
-    # per-page scales, then transpose only the gathered block into the
-    # head-major layout the f32 decode reference wants — never the whole
-    # arena. This keeps the per-step cost over the dense int8 path to one
-    # gather + one small transpose (~10% at the serving shapes, see
+    # XLA path: gather from the arena FIRST (the gathered (B, MP, ps, KV, hd)
+    # block is per-request-sized), dequant with the per-page scales, then
+    # transpose only the gathered block into the head-major layout the f32
+    # decode reference wants — never the whole arena. This keeps the
+    # per-step cost over the dense int8 path to one gather + one small
+    # transpose (~10% at the serving shapes, see
     # BENCH_serving.json#paged.step_parity).
     B, MP = page_table.shape
-    _, ps, KV, hd = k_pages.shape
+    _, ps, _ = k_pages.shape
+    KV, hd = k_scale.shape[1], q.shape[-1]
 
     def gathered(pages, scale):
-        g = pages[page_table].astype(jnp.float32)   # (B, MP, ps, KV, hd)
+        g = pages[page_table].reshape(B, MP, ps, KV, hd).astype(jnp.float32)
         g = g * scale[page_table][:, :, None, :, None]
         return g.transpose(0, 3, 1, 2, 4).reshape(B, KV, MP * ps, hd)
 
@@ -135,7 +142,8 @@ def paged_verify_attention(q, k_pages, v_pages, k_scale, v_scale, page_table,
                            interpret: bool = False):
     """Multi-position paged attention for the speculative verify window.
 
-    q: (B, T, H, hd) — the T = k+1 window positions' queries; window position
+    q: (B, T, H, hd) — the T = k+1 window positions' queries; the arena,
+    scales and table as ``paged_decode_attention`` takes them; window position
     j sits at absolute position ``base_len + j`` and therefore attends
     ``base_len + j + 1`` keys. All T positions' K/V must already be written
     into the arena: codes at positions a query must not see are gathered,
@@ -153,8 +161,8 @@ def paged_verify_attention(q, k_pages, v_pages, k_scale, v_scale, page_table,
     ``base_len + j + 1`` with the same NEG_INF + softmax treatment the
     decode reference uses, so only matmul batching (an invariance the
     chunked-prefill plane already relies on) separates it from T unrolled
-    single-token calls. The Pallas backend falls back to T unrolled
-    single-token kernel calls — correct everywhere, fused later."""
+    single-token calls. The Pallas backend makes T single-token kernel calls,
+    each reading the arena in place — correct everywhere, fused later."""
     T = q.shape[1]
     b = _resolve(backend)
     if b == "pallas":
@@ -165,13 +173,14 @@ def paged_verify_attention(q, k_pages, v_pages, k_scale, v_scale, page_table,
                 for j in range(T)]
         return jnp.stack(outs, axis=1)
     B, MP = page_table.shape
-    _, ps, KV, hd = k_pages.shape
-    H = q.shape[2]
+    _, ps, _ = k_pages.shape
+    H, hd = q.shape[2:]
+    KV = k_scale.shape[1]
     G = H // KV
     S = MP * ps
 
     def gathered(pages, scale):
-        g = pages[page_table].astype(jnp.float32)   # (B, MP, ps, KV, hd)
+        g = pages[page_table].reshape(B, MP, ps, KV, hd).astype(jnp.float32)
         g = g * scale[page_table][:, :, None, :, None]
         return g.transpose(0, 3, 1, 2, 4).reshape(B, KV, S, hd)
 
@@ -194,7 +203,7 @@ def paged_verify_attention(q, k_pages, v_pages, k_scale, v_scale, page_table,
 def gather_prefix_kv(k_pages, v_pages, k_scale, v_scale, page_table):
     """Dequantized prefix K/V gather, model layout (chunked prefill).
 
-    k_pages/v_pages: (num_pages, ps, KV, hd) int8 arena; k_scale/v_scale:
+    k_pages/v_pages: (num_pages, ps, KV * hd) int8 arena; k_scale/v_scale:
     (num_pages, KV) per-page scales; page_table: (B, P) int32 prefix pages
     (positions past a row's true prefix length may point at the trash page —
     the attention mask is responsible for hiding them). Returns float32
@@ -205,10 +214,12 @@ def gather_prefix_kv(k_pages, v_pages, k_scale, v_scale, page_table):
     handful of prefix pages), so there is nothing for a Pallas kernel to win
     here — the arena is never transposed wholesale."""
     B, P = page_table.shape
-    _, ps, KV, hd = k_pages.shape
+    _, ps, W = k_pages.shape
+    KV = k_scale.shape[1]
+    hd = W // KV
 
     def gather(pages, scale):
-        g = pages[page_table].astype(jnp.float32)    # (B, P, ps, KV, hd)
+        g = pages[page_table].reshape(B, P, ps, KV, hd).astype(jnp.float32)
         g = g * scale[page_table][:, :, None, :, None]
         return g.reshape(B, P * ps, KV, hd)
 

@@ -282,8 +282,8 @@ def self_attention_decode(p, x, cache, cfg, shard, *, pos=None, pos3=None,
                       -127, 127).astype(jnp.int8)
         vq = jnp.clip(jnp.round(vf / vs[:, :, None]),
                       -127, 127).astype(jnp.int8)
-        k_pages = cache["k"].at[page, off].set(kq)
-        v_pages = cache["v"].at[page, off].set(vq)
+        k_pages = cache["k"].at[page, off].set(kq.reshape(B, -1))
+        v_pages = cache["v"].at[page, off].set(vq.reshape(B, -1))
         k_sc = cache["k_scale"].at[page].set(ks)
         v_sc = cache["v_scale"].at[page].set(vs)
         k_max = jnp.maximum(cache["k_max"], jnp.max(jnp.abs(kf), axis=-1))
@@ -370,8 +370,8 @@ def self_attention_verify(p, x, cache, cfg, shard, *, pos=None, pos3=None,
     kq = jnp.clip(jnp.round(kf / ks[..., None]), -127, 127).astype(jnp.int8)
     vq = jnp.clip(jnp.round(vf / vs[..., None]), -127, 127).astype(jnp.int8)
     pf, of = page.reshape(-1), off.reshape(-1)
-    k_pages = cache["k"].at[pf, of].set(kq.reshape((B * T,) + kq.shape[2:]))
-    v_pages = cache["v"].at[pf, of].set(vq.reshape((B * T,) + vq.shape[2:]))
+    k_pages = cache["k"].at[pf, of].set(kq.reshape(B * T, -1))
+    v_pages = cache["v"].at[pf, of].set(vq.reshape(B * T, -1))
     # duplicate page indices across a row's positions carry identical scale
     # values (same page => same in_old branch), so last-write-wins is exact
     k_sc = cache["k_scale"].at[pf].set(ks.reshape(B * T, -1))

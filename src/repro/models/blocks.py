@@ -87,7 +87,10 @@ def sublayer_cache_spec(cfg: ModelConfig, lay: SubLayer, batch: int, s_max: int,
     ``paged``: the paged serving pool layout (``core.decode_engine`` with
     ``paged=True``) — instead of one dense (batch, s_max) region per slot,
     self-attention K/V live in a global arena of ``num_pages`` fixed-size
-    pages shared by all slots (copy-on-write prefix sharing maps one page
+    pages, ``(num_pages, page_size, kv * hd)`` int8 (one row per token, the
+    KV heads side by side: the paged decode kernel reads a page as one
+    contiguous block, and the row width keeps the TPU's default layout
+    row-major where ``hd`` alone is under 128 lanes), shared by all slots (copy-on-write prefix sharing maps one page
     into several tables), addressed through a per-slot ``page_table``
     (int32 arena page ids; entries past a stream's length stay 0, a valid —
     masked — index). Scales are per (page, kv-head) — each page is
@@ -110,11 +113,11 @@ def sublayer_cache_spec(cfg: ModelConfig, lay: SubLayer, batch: int, s_max: int,
             "paged pools are int8 self-attention only"
         mp = -(-s_max // page_size)                 # page-table width
         spec = {
-            "k": ParamSpec((num_pages, page_size, kv, hd),
-                           (None, None, "kv_heads", None),
+            "k": ParamSpec((num_pages, page_size, kv * hd),
+                           (None, None, "kv_heads"),
                            init="zeros", dtype=kv_dt),
-            "v": ParamSpec((num_pages, page_size, kv, hd),
-                           (None, None, "kv_heads", None),
+            "v": ParamSpec((num_pages, page_size, kv * hd),
+                           (None, None, "kv_heads"),
                            init="zeros", dtype=kv_dt),
             "k_scale": ParamSpec((num_pages, kv), (None, "kv_heads"),
                                  init="zeros", dtype=jnp.float32),

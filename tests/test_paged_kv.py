@@ -49,33 +49,87 @@ def _fm(cfg, impl="segmented", na=3):
 # ---------------- kernel parity ----------------
 
 def _paged_case(seed=0, B=3, H=8, KV=2, hd=16, ps=8, P=11, MP=4,
-                lens=(9, 25, 1)):
+                lens=(9, 25, 1), shared=0, free_rows=()):
+    """Random int8 pages in the oracle's head-major layout (P, KV, ps, hd),
+    disjoint pages per stream; ``shared`` maps row 0's first pages into row
+    1 (prefix sharing); ``free_rows`` keep the all-trash table of a free
+    slot (their lengths are garbage)."""
     rng = np.random.RandomState(seed)
     q = jnp.asarray(rng.randn(B, H, hd).astype(np.float32))
     kp = jnp.asarray(rng.randint(-127, 128, (P, KV, ps, hd)).astype(np.int8))
     vp = jnp.asarray(rng.randint(-127, 128, (P, KV, ps, hd)).astype(np.int8))
     ks = jnp.asarray(rng.rand(P, KV).astype(np.float32) * 0.05 + 1e-3)
     vs = jnp.asarray(rng.rand(P, KV).astype(np.float32) * 0.05 + 1e-3)
-    pt = np.zeros((B, MP), np.int32)           # disjoint pages per stream
+    pt = np.zeros((B, MP), np.int32)
     free = list(range(1, P))
     rng.shuffle(free)
     for b in range(B):
-        for j in range(-(-int(lens[b]) // ps)):
+        if b in free_rows:
+            continue
+        for j in range(shared if b == 1 else 0, -(-int(lens[b]) // ps)):
             pt[b, j] = free.pop()
+    pt[1, :shared] = pt[0, :shared]
     return q, kp, vp, ks, vs, jnp.asarray(pt), jnp.asarray(
         np.asarray(lens, np.int32))
 
 
+def _model_layout(pages):
+    """Oracle pages (P, KV, ps, hd) -> the arena as the pool stores it,
+    (P, ps, KV * hd): one row per token, the KV heads side by side."""
+    P, KV, ps, hd = pages.shape
+    return pages.transpose(0, 2, 1, 3).reshape(P, ps, KV * hd)
+
+
 @pytest.mark.parametrize("window", [None, 6])
 def test_paged_kernel_interpret_matches_ref(window):
-    """Pallas paged decode (interpret mode on CPU) vs the jnp gather oracle."""
+    """Pallas paged decode (interpret mode on CPU) on the model-layout arena
+    vs the jnp gather oracle."""
     q, kp, vp, ks, vs, pt, lens = _paged_case()
     want = ref.paged_decode_attention_ref(q, kp, vp, ks, vs, pt, lens,
                                           window=window)
-    got = paged_decode_attention(q, kp, vp, ks, vs, pt, lens, window=window,
-                                 interpret=True)
+    got = paged_decode_attention(q, _model_layout(kp), _model_layout(vp), ks,
+                                 vs, pt, lens, window=window, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
+
+
+# stablelm's widths (G = 1, hd 64) give 8 pages of 16 a block, so a stream
+# of 256 keys walks two blocks; qwen2-7b's (KV 4, H 28, hd 128) give 32
+PAGED_CASES = {
+    "lengths": dict(B=4, H=32, KV=32, hd=64, ps=16, P=24, MP=16,
+                    lens=(1, 16, 17, 256)),
+    "shared_prefix": dict(B=3, H=32, KV=32, hd=64, ps=16, P=16, MP=8,
+                          lens=(40, 50, 9), shared=2),
+    "free_row": dict(B=3, H=32, KV=32, hd=64, ps=16, P=16, MP=8,
+                     lens=(70, 37, 20), free_rows=(1,)),
+    "mha_hd64": dict(B=3, H=8, KV=8, hd=64, ps=16, P=100, MP=48,
+                     lens=(700, 33, 5)),
+    "gqa7_hd128": dict(B=3, H=28, KV=4, hd=128, ps=16, P=130, MP=80,
+                       lens=(1280, 600, 17)),
+    "window": dict(B=3, H=32, KV=32, hd=64, ps=16, P=26, MP=16,
+                   lens=(1, 100, 256), window=40),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAGED_CASES))
+def test_paged_kernel_interpret_cases(case):
+    """The kernel (interpret mode) against the oracle across lengths at
+    page and block edges, shared prefix pages, a free row (first entry the
+    trash page: zeros, whatever its length), MHA and GQA widths and a
+    sliding window that skips whole blocks."""
+    kw = dict(PAGED_CASES[case])
+    window = kw.pop("window", None)
+    q, kp, vp, ks, vs, pt, lens = _paged_case(seed=7, **kw)
+    want = np.array(ref.paged_decode_attention_ref(
+        q, kp, vp, ks, vs, pt, lens, window=window))
+    got = np.asarray(paged_decode_attention(
+        q, _model_layout(kp), _model_layout(vp), ks, vs, pt, lens,
+        window=window, interpret=True))
+    for b in kw.get("free_rows", ()):
+        assert np.all(got[b] == 0.0)
+        want[b] = 0.0
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
 def test_paged_ref_matches_dense_int8_ref():
@@ -110,11 +164,10 @@ def test_paged_ref_matches_dense_int8_ref():
 
 
 def test_ops_paged_dispatch_model_layout():
-    """The ops wrapper adapts the model-layout arena (P, ps, KV, hd)."""
+    """The ops wrapper reads the model-layout arena (P, ps, KV * hd)."""
     q, kp, vp, ks, vs, pt, lens = _paged_case(seed=2)
-    got = ops.paged_decode_attention(q, kp.transpose(0, 2, 1, 3),
-                                     vp.transpose(0, 2, 1, 3), ks, vs, pt,
-                                     lens)
+    got = ops.paged_decode_attention(q, _model_layout(kp), _model_layout(vp),
+                                     ks, vs, pt, lens)
     want = ref.paged_decode_attention_ref(q, kp, vp, ks, vs, pt, lens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-6, rtol=1e-6)

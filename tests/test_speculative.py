@@ -93,6 +93,12 @@ def _verify_case(seed=0, B=3, T=5, H=8, KV=2, hd=16, ps=8, P=11, MP=5,
         np.asarray(lens, np.int32))
 
 
+def _model_layout(pages):
+    """Oracle pages (P, KV, ps, hd) -> the arena as the pool stores it."""
+    P, KV, ps, hd = pages.shape
+    return pages.transpose(0, 2, 1, 3).reshape(P, ps, KV * hd)
+
+
 @pytest.mark.parametrize("window", [None, 6])
 def test_verify_attention_matches_unrolled_ref(window):
     """The fused one-gather XLA verify path must match T independent
@@ -102,8 +108,8 @@ def test_verify_attention_matches_unrolled_ref(window):
     want = ref.paged_verify_attention_ref(q, kp, vp, ks, vs, pt, base,
                                           window=window)
     got = ops.paged_verify_attention(
-        q, kp.transpose(0, 2, 1, 3), vp.transpose(0, 2, 1, 3), ks, vs, pt,
-        base, window=window)
+        q, _model_layout(kp), _model_layout(vp), ks, vs, pt, base,
+        window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
 
@@ -114,8 +120,8 @@ def test_verify_attention_pallas_fallback_matches_ref():
     q, kp, vp, ks, vs, pt, base = _verify_case(seed=3, T=3)
     want = ref.paged_verify_attention_ref(q, kp, vp, ks, vs, pt, base)
     got = ops.paged_verify_attention(
-        q, kp.transpose(0, 2, 1, 3), vp.transpose(0, 2, 1, 3), ks, vs, pt,
-        base, backend="pallas", interpret=True)
+        q, _model_layout(kp), _model_layout(vp), ks, vs, pt, base,
+        backend="pallas", interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
 
