@@ -1,5 +1,6 @@
 """Whether the window's answers are correct: a seeded sample of what the
-timed path served, against the float32 reference (``bench/reference``).
+timed path served, against the float32 reference (the module the
+configuration names under ``reference``).
 
 Numbers compared, each against its limit in ``workloads/<cell>.json``:
 
@@ -16,15 +17,14 @@ Numbers compared, each against its limit in ``workloads/<cell>.json``:
   mean square of the reference's answers.
 
 The reference runs after the program's state is freed, one request at a
-time, and regenerates the weights from the seed.
+time, and regenerates the weights from the seed (the configuration's
+``weights`` module).
 """
 from __future__ import annotations
 
 import gc
 
 import numpy as np
-
-from bench import weights
 
 
 def _sample(recs, kind: str, rng, *, want_tokens: int = 0,
@@ -64,21 +64,15 @@ def readings(cell, seed: int, recs, payloads, *,
     program's place."""
     import jax.numpy as jnp
 
-    from bench.reference import transformer as ref
-
+    ref = cell.part("reference")
     conf = cell.config
     m = conf["model"]
     chk = conf["check"]
-    tasks = conf["tasks"]
-    theta = float(m.get("rope_theta", 10000.0))
-    causal = not m.get("is_representation", False)
     rng = np.random.default_rng([seed, 7])
     gen = _sample(recs, "generative", rng, want_tokens=chk["gen_tokens"],
                   max_requests=chk["gen_requests"])
     pooled = _sample(recs, "pooled", rng, max_requests=chk["pooled_requests"])
-    params, adapters, heads = weights.make(
-        seed, m, rank=tasks["lora_rank"], adapters=tasks["count"],
-        heads=tasks["count"], classes=tasks["head_classes"])
+    params, adapters, heads = cell.make_weights(seed)
     out = {}
     if gen:
         eng = conf["engine"]
@@ -93,14 +87,13 @@ def readings(cell, seed: int, recs, payloads, *,
                 cand = np.zeros(width, np.int32)
                 at = slice(len(p) - 1, len(p) - 1 + len(s))
                 cand[at] = s
-                args = (params, adapters[_task(r)], jnp.asarray(seq))
+                args = (m, params, adapters[_task(r)], jnp.asarray(seq))
                 if prec == "f32":
-                    gap, _ = ref.token_scores(*args, jnp.asarray(cand),
-                                              theta=theta)
+                    gap, _ = ref.token_scores(*args, jnp.asarray(cand))
                 else:
                     _, pick = ref.token_scores(*args, jnp.asarray(cand),
-                                               theta=theta, precision=prec)
-                    gap, _ = ref.token_scores(*args, pick, theta=theta)
+                                               precision=prec)
+                    gap, _ = ref.token_scores(*args, pick)
                 worst = max(worst, float(np.max(np.asarray(gap)[at])))
             out[f"gen_logit_gap@{prec}"] = worst
         out["gen_checked_tokens"] = int(sum(r["streamed"] for r in gen))
@@ -112,7 +105,7 @@ def readings(cell, seed: int, recs, payloads, *,
             x = jnp.asarray(payloads[r["payload"]])
             t = _task(r)
             want[r["rid"]] = np.asarray(ref.pooled_answer(
-                params, adapters[t], heads[t], x, causal=causal, theta=theta))
+                m, params, adapters[t], heads[t], x))
         scale = float(np.sqrt(np.mean(np.square(np.stack(list(want.values()))))))
         for prec in precisions:
             worst = 0.0
@@ -122,9 +115,8 @@ def readings(cell, seed: int, recs, payloads, *,
                 else:
                     t = _task(r)
                     got = np.asarray(ref.pooled_answer(
-                        params, adapters[t], heads[t],
-                        jnp.asarray(payloads[r["payload"]]), causal=causal,
-                        theta=theta, precision=prec))
+                        m, params, adapters[t], heads[t],
+                        jnp.asarray(payloads[r["payload"]]), precision=prec))
                 worst = max(worst, float(np.max(np.abs(got - want[r["rid"]]))))
             out[f"pooled_err@{prec}"] = worst / scale
         out["pooled_checked_requests"] = len(pooled)

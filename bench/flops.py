@@ -1,4 +1,6 @@
-"""Operations and bytes of the served work, computed from shapes.
+"""Operations and bytes of the served work of a dense transformer stack,
+computed from shapes: the ``ops`` module of such a configuration
+(``bench/spec.py`` gives the contract).
 
 ``m`` is a configuration's ``model`` dict (``configs/<config>.json``) and
 ``rank`` its LoRA rank. Counts are the matrix multiplications the model
@@ -54,10 +56,11 @@ def decode_ops(m: dict, rank: int, prompt: int, tokens: int) -> float:
         + attn_pairs_ops(m, pairs)
 
 
-def pooled_ops(m: dict, rank: int, rows: int, causal: bool,
-               head_out: int) -> float:
-    """One pooled request: the backbone over ``rows`` rows and the task's
-    linear head over the pooled feature."""
+def pooled_ops(m: dict, rank: int, rows: int, head_out: int) -> float:
+    """One pooled request: the backbone over ``rows`` rows (causal unless
+    the model is a representation model) and the task's linear head over
+    the pooled feature."""
+    causal = not m.get("is_representation", False)
     pairs = rows * (rows + 1) / 2 if causal else rows * rows
     return rows * dense_per_token(m, rank) + attn_pairs_ops(m, pairs) \
         + 2 * m["d_model"] * head_out

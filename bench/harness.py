@@ -6,13 +6,19 @@ The system under test is driven only through its public entry points:
 ``ServeLoop.warmup`` and ``ServeLoop.run``. In a traced run (``trace=True``)
 the benchmark wraps the calls the loop makes into each layer with host
 spans (``jax.profiler.TraceAnnotation``, on the device trace's clock) and
-records what each call did; nothing inside the program changes. With
+records what each call did; nothing inside the program changes. It also
+turns the program's own span recorder (``repro.core.tracing``) on for the
+window and hands its spans and the window's deltas of the loop's and the
+engine's counters to the readers as ``run.program``; an untraced run
+leaves the recorder off. With
 ``BENCH_TRACE_DUMP=<file>`` a traced run also writes the trace's planes and
 lines and a 50 ms slice of its events there (how ``bench/tests/data`` was
 recorded).
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import gc
 import os
 import shutil
@@ -23,7 +29,7 @@ import types
 
 import numpy as np
 
-from bench import correct, spec, traffic, weights
+from bench import correct, spec, traffic
 
 # after the last arrival the window stays open this long for the queue to
 # drain, unless the cell's workload file sets ``drain_s`` (0: the window
@@ -35,10 +41,21 @@ def log(*a):
     print(*a, file=sys.stderr, flush=True)
 
 
+def _frozen(v):
+    return tuple(_frozen(x) for x in v) if isinstance(v, list) else v
+
+
+def model_kwargs(model: dict) -> dict:
+    """A configuration's ``model`` dict as ``ModelConfig``'s keywords: JSON
+    lists (``block_pattern``) become tuples, so the frozen config hashes."""
+    return {k: _frozen(v) for k, v in model.items()}
+
+
 def build(cell: spec.Cell, seed: int):
     """The server, its loop and what the reference needs to know of the
-    tasks: one ``PhysicalFM`` with the benchmark's weights, every task's
-    LoRA adapter, a linear head on every task that sends pooled requests."""
+    tasks: one ``PhysicalFM`` with the configuration's weights (its
+    ``weights`` module), every task's LoRA adapter, a linear head on every
+    task that sends pooled requests."""
     import jax
 
     from repro.configs.base import ModelConfig
@@ -48,7 +65,7 @@ def build(cell: spec.Cell, seed: int):
     from repro.core.vfm import TaskExtensions
 
     conf = cell.config
-    model = ModelConfig(name=conf["name"], **conf["model"])
+    model = ModelConfig(name=conf["name"], **model_kwargs(conf["model"]))
     tasks = conf["tasks"]
     pooled_rows = conf.get("pooled_rows", 1)
     fm = PhysicalFM(model, seed=0, lora_rank=tasks["lora_rank"],
@@ -56,9 +73,7 @@ def build(cell: spec.Cell, seed: int):
     # the program's own random init is replaced by the benchmark's
     fm.params = None
     gc.collect()
-    params, adapters, heads = weights.make(
-        seed, conf["model"], rank=tasks["lora_rank"], adapters=tasks["count"],
-        heads=tasks["count"], classes=tasks["head_classes"])
+    params, adapters, heads = cell.make_weights(seed)
     fm.params = params
     for i, tree in enumerate(adapters):
         fm.adapters.add(f"lora{i}", tree)
@@ -227,6 +242,40 @@ class Profiler:
         shutil.rmtree(self.dir, ignore_errors=True)
 
 
+def counters(loop) -> dict:
+    """The loop's and the engine's counters the program's readers take,
+    where the program has them."""
+    got = {"ticks_admit": loop.ticks["admit"],
+           "admit_ready": getattr(loop, "admit_ready", None)}
+    eng = loop.srv.engines.get(loop.fm_id)
+    if eng is not None:
+        got.update(steps=eng.steps,
+                   live_slot_steps=getattr(eng, "live_slot_steps", None))
+    return {k: v for k, v in got.items() if v is not None}
+
+
+@contextlib.contextmanager
+def recording(loop):
+    """While open, the program records its spans (``repro.core.tracing``);
+    at the close the dict this yields holds them and the deltas of
+    ``counters`` over the block, as ``{"spans", "counters"}``."""
+    from repro.core import tracing
+    program = {}
+    c0 = counters(loop)
+    tracing.take()
+    tracing.enable()
+    try:
+        yield program
+    finally:
+        tracing.disable()
+    c1 = counters(loop)
+    program["counters"] = {n: c1[n] - c0[n] for n in c1 if n in c0}
+    eng = loop.srv.engines.get(loop.fm_id)
+    if eng is not None:
+        program["counters"]["num_slots"] = eng.num_slots
+    program["spans"] = tracing.take()
+
+
 def streamed(eng) -> dict[int, list]:
     """The tokens each unfinished stream has returned to the host so far:
     the live slots and the preempted streams waiting to resume."""
@@ -333,8 +382,10 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace_on: bool, *,
     t_start = time.perf_counter()
     setup_s = t_start - t_proc0
     log(f"set-up: {setup_s:.3f} s; compiles and cache {c0}")
-    loop.run(reqs, max_wall=seconds + drain, on_tick=on_tick)
-    t_end = time.perf_counter()
+    with recording(loop) if trace_on else contextlib.nullcontext() \
+            as program:
+        loop.run(reqs, max_wall=seconds + drain, on_tick=on_tick)
+        t_end = time.perf_counter()
     c1 = stats.snapshot()
     if prof:
         prof.finish()
@@ -370,7 +421,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace_on: bool, *,
         model=cell.config["model"], peaks=spec.peaks(dev.device_kind,
                                                      cell.bench_dir)
         if dev.platform == "tpu" else None,
-        calls=spans.calls if spans else None, trace=None)
+        calls=spans.calls if spans else None, trace=None, program=program,
+        ops=cell.part("ops"))
     result = {"device": {"platform": dev.platform, "kind": dev.device_kind,
                          "count": len(jax.devices()),
                          "memory_peak_bytes": int(peak)}}
@@ -414,6 +466,12 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace_on: bool, *,
     result["failed"] = sum(r["status"] != "ok" for r in recs)
     result["unfinished"] = sum(r["status"] == "unfinished" for r in recs)
     result["window_compiles"] = window_compiles
+    if program:
+        result["program"] = {
+            "counters": program["counters"],
+            "spans": dict(collections.Counter(s.name
+                                              for s in program["spans"]))}
+        log(f"program: {result['program']}")
     checks, info = correct.check(cell, seed, recs, payloads,
                                  controls=controls)
     log(f"checked against the reference: {info}")

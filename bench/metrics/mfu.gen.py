@@ -1,14 +1,14 @@
 """Whole model step: the model operations of the work done in the window
 (the prefill and the tokens returned of every stream, finished or still
-decoding at the close, and the finished pooled requests; from shapes in
-bench/flops.py) over the window and the chip's bfloat16 peak, in %."""
-from bench import flops, stats
+decoding at the close, and the finished pooled requests; from shapes, by
+the configuration's ``ops`` module) over the window and the chip's
+bfloat16 peak, in %."""
+from bench import stats
 
 
 def model_ops(run, kinds):
-    m, conf = run.model, run.config
+    m, conf, ops = run.model, run.config, run.ops
     rank = conf["tasks"]["lora_rank"]
-    causal = not m.get("is_representation", False)
     total = 0.0
     for r in run.recs:
         if r["kind"] not in kinds:
@@ -17,11 +17,11 @@ def model_ops(run, kinds):
             if r["status"] not in ("ok", "unfinished") or not r["streamed"]:
                 continue
             p = min(r["prompt_len"], conf["engine"]["prompt_buckets"][-1])
-            total += flops.prefill_ops(m, rank, p) \
-                + flops.decode_ops(m, rank, p, r["streamed"])
+            total += ops.prefill_ops(m, rank, p) \
+                + ops.decode_ops(m, rank, p, r["streamed"])
         elif stats.ok(r):
-            total += flops.pooled_ops(m, rank, conf["pooled_rows"], causal,
-                                      conf["tasks"]["head_classes"])
+            total += ops.pooled_ops(m, rank, conf["pooled_rows"],
+                                    conf["tasks"]["head_classes"])
     return total
 
 

@@ -1,10 +1,10 @@
 """Kernels: the paged int8 decode attention kernel's share of its roofline,
 in %. Bytes are those each decode step of the traced chunks references
-(bench/flops.py: the K/V pages covering each live stream, the gathered
-scales, q and out), over the HBM peak, against the kernel's device time in
+(the configuration's ``ops`` module: the K/V pages covering each live
+stream, the gathered scales, q and out), over the HBM peak, against the kernel's device time in
 the trace. The kernel is memory bound: its operations per byte are far
 below the chip's ratio of peaks."""
-from bench import flops, trace
+from bench import trace
 
 # the Pallas kernel's operation name in the device trace
 KERNEL = r"paged_decode_attention"
@@ -24,7 +24,7 @@ def read(run):
         if not c["traced"]:
             continue
         for s in range(c["steps"]):
-            total += flops.paged_attn_bytes(
+            total += run.ops.paged_attn_bytes(
                 run.model, [n + s for n in c["live"]], page_size=ps,
                 slots=eng["num_slots"], max_pages=-(-s_max // ps))
     if not total:

@@ -3,7 +3,10 @@
 Written from the equations, in straightforward ``jax.numpy``, with every
 product at ``Precision.HIGHEST`` (on a TPU a float32 product otherwise runs
 as one bfloat16 pass). It imports nothing of the program: it reads the
-weights that ``bench/weights.py`` makes from the seed, by name.
+weights that ``bench/weights.py`` makes from the seed, by name. It is the
+``reference`` module of a dense transformer configuration (``bench/spec.py``
+gives the contract): ``token_scores`` and ``pooled_answer`` take the
+configuration's ``model`` dict first.
 
 Each layer, for input x (rows, d):
 
@@ -123,12 +126,33 @@ def stack(params, adapter, x, *, causal: bool, theta: float,
     return rmsnorm(x, params["final_norm"])
 
 
-@functools.partial(jax.jit, static_argnames=("theta", "precision"))
-def token_scores(params, adapter, tokens, cand, *, theta: float,
+def _theta(model: dict) -> float:
+    return float(model.get("rope_theta", 10000.0))
+
+
+def token_scores(model: dict, params, adapter, tokens, cand, *,
                  precision: str = "f32"):
     """A decoder over ``tokens`` (S,) int32. For each position i returns
     (logit max - logit of ``cand[i]``, argmax): how far the candidate lies
-    below the best token, and which token that is."""
+    below the best token, and which token that is. ``model`` is the
+    configuration's ``model`` dict."""
+    return _token_scores(params, adapter, tokens, cand, theta=_theta(model),
+                         precision=precision)
+
+
+def pooled_answer(model: dict, params, adapter, head, rows, *,
+                  precision: str = "f32"):
+    """A pooled request: the stack over ``rows`` (R, d), the mean over the
+    rows, the task's head. Causal unless the model is a representation
+    model."""
+    return _pooled_answer(params, adapter, head, rows,
+                          causal=not model.get("is_representation", False),
+                          theta=_theta(model), precision=precision)
+
+
+@functools.partial(jax.jit, static_argnames=("theta", "precision"))
+def _token_scores(params, adapter, tokens, cand, *, theta: float,
+                  precision: str):
     x = params["embed"][tokens]
     h = stack(params, adapter, x, causal=True, theta=theta,
               precision=precision)
@@ -139,10 +163,8 @@ def token_scores(params, adapter, tokens, cand, *, theta: float,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "theta", "precision"))
-def pooled_answer(params, adapter, head, rows, *, causal: bool, theta: float,
-                  precision: str = "f32"):
-    """A pooled request: the stack over ``rows`` (R, d), the mean over the
-    rows, the task's head."""
+def _pooled_answer(params, adapter, head, rows, *, causal: bool,
+                   theta: float, precision: str):
     h = stack(params, adapter, rows, causal=causal, theta=theta,
               precision=precision)
     return jnp.einsum("d,dc->c", jnp.mean(h, axis=0), head, precision=HI)

@@ -1,8 +1,8 @@
-"""The program's spans and counters as ``bench/tools/program_spans.py``
-reads them: the ``bench/metrics`` readers it loads, on synthetic runs, on a
+"""The program's spans and counters as a traced run reads them: the
+``bench/metrics`` readers of ``run.program`` on synthetic runs, on a
 program without the counters and on a run that carries none, idle gaps
 named by program spans nested in the benchmark's, the device trace keeping
-both, and a small run on the CPU in which each reading is there."""
+both, and a small traced run on the CPU in which each reading is there."""
 from __future__ import annotations
 
 import glob
@@ -17,13 +17,16 @@ import pytest
 REPO = pathlib.Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(REPO), str(REPO / "src")]
 
-from bench import harness, trace  # noqa: E402
+from bench import harness, spec, trace  # noqa: E402
+from bench.metrics.decode_host_ms import chunk_parts  # noqa: E402
 from bench.tests.small import small_cell  # noqa: E402
-from bench.tools import program_spans as ps  # noqa: E402
 from repro.core import tracing  # noqa: E402
 
 DATA = pathlib.Path(__file__).parent / "data"
 CELL = "stablelm-1.6b.chat-gen-overload"
+PROGRAM_METRICS = ("admit_when_ready", "decode_slots_live", "decode_host_ms")
+READERS = {name: spec.reader(REPO / "bench", name)
+           for name in PROGRAM_METRICS}
 
 
 def _chunk(t0, t1, steps, live, kids=()):
@@ -61,25 +64,26 @@ def _synthetic():
     ("decode_host_ms", 1e3 * (0.05 + 0.02) / 2),
 ])
 def test_readers_on_a_synthetic_run(name, want):
-    assert ps.READERS[name](_synthetic()) == pytest.approx(want)
+    assert READERS[name](_synthetic()) == pytest.approx(want)
 
 
-@pytest.mark.parametrize("name", sorted(ps.READERS))
+@pytest.mark.parametrize("name", sorted(READERS))
 def test_readers_read_nothing_without_the_programs_counters(name):
     # the parent's program: no recorder, no admit_ready, no live_slot_steps
     run = types.SimpleNamespace(program={
         "spans": None, "counters": {"ticks_admit": 4, "steps": 8}})
-    assert ps.READERS[name](run) is None
+    assert READERS[name](run) is None
 
 
-@pytest.mark.parametrize("name", sorted(ps.READERS))
+@pytest.mark.parametrize("name", sorted(READERS))
 def test_readers_read_nothing_from_a_run_without_the_program(name):
-    # the harness's own run, which carries no ``program``
-    assert ps.READERS[name](types.SimpleNamespace(trace=None)) is None
+    # an untraced run, which carries no ``program``
+    assert READERS[name](types.SimpleNamespace(trace=None,
+                                               program=None)) is None
 
 
 def test_chunk_parts_split_a_chunk_by_its_children():
-    chunks, parts = ps.chunk_parts(_synthetic().program["spans"])
+    chunks, parts = chunk_parts(_synthetic().program["spans"])
     assert chunks == [0, 5]
     assert parts[0]["fmplex.chunk_sync"] == pytest.approx(1.4)
     assert parts[0]["fmplex.admit"] == pytest.approx(0.05)
@@ -105,35 +109,49 @@ def test_the_device_trace_keeps_program_spans(tmp_path):
     import jax.numpy as jnp
     tracing.enable()
     try:
-        with ps.recording():
-            jax.profiler.start_trace(str(tmp_path))
-            try:
-                with jax.profiler.TraceAnnotation("bench.trace_window"):
-                    with jax.profiler.TraceAnnotation("bench.decode_chunk"):
-                        with tracing.span("fmplex.decode_chunk"):
-                            jnp.ones(8).sum().block_until_ready()
-            finally:
-                jax.profiler.stop_trace()
-            path = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
-            ev = trace.collect(path[0])
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with jax.profiler.TraceAnnotation("bench.trace_window"):
+                with jax.profiler.TraceAnnotation("bench.decode_chunk"):
+                    with tracing.span("fmplex.decode_chunk"):
+                        jnp.ones(8).sum().block_until_ready()
+                with jax.profiler.TraceAnnotation("other.span"):
+                    jnp.ones(8).sum().block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        path = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+        ev = trace.collect(path[0])
     finally:
         tracing.disable()
         tracing.take()
     names = {e[0] for e in ev["host"]}
     assert {"bench.trace_window", "bench.decode_chunk",
             "fmplex.decode_chunk"} <= names
-    assert trace.SPAN_PREFIX == "bench."         # restored at the close
+    assert "other.span" not in names
 
 
 def test_a_small_run_reads_each_program_metric():
     cell = small_cell(CELL, drain=False)
-    with ps.recording() as program:
-        res = harness.run_cell(cell, 2**33 + 23, 2.0, False,
-                               t_proc0=time.perf_counter())
+    assert {m["name"] for m in cell.metrics_layer} >= set(PROGRAM_METRICS)
+    res = harness.run_cell(cell, 2**33 + 23, 2.0, True,
+                           t_proc0=time.perf_counter())
     assert res["attempted"] > 0
-    got = ps.readings(program)
+    got = {n: res["metrics"][n]["value"] for n in PROGRAM_METRICS}
     assert got["admit_when_ready"] > 0 and got["decode_slots_live"] > 0
     assert got["admit_when_ready"] <= 100 and got["decode_slots_live"] <= 100
     assert got["decode_host_ms"] > 0
-    assert got["spans"]["fmplex.tick"] >= got["counters"]["ticks_admit"]
+    program = res["program"]
+    assert program["spans"]["fmplex.tick"] >= \
+        program["counters"]["ticks_admit"] > 0
     assert not tracing._REC.on
+
+
+def test_an_untraced_run_leaves_the_recorder_off(monkeypatch):
+    enabled = []
+    monkeypatch.setattr(tracing, "enable", lambda: enabled.append(1))
+    cell = small_cell(CELL, drain=False)
+    res = harness.run_cell(cell, 2**33 + 29, 1.0, False,
+                           t_proc0=time.perf_counter())
+    assert res["attempted"] > 0 and "program" not in res
+    assert not enabled and not tracing._REC.on
+    assert not set(PROGRAM_METRICS) & set(res["metrics"])

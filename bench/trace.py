@@ -4,7 +4,9 @@
 small dict of events, in nanoseconds on the trace's one clock:
 
     {"device": [[name, start, dur], ...],   operations on each chip, by chip
-     "host":   [[name, start, dur], ...]}   the benchmark's own host spans
+     "host":   [[name, start, dur], ...]}   host spans: the benchmark's
+                                             (``bench.*``) and the
+                                             program's (``fmplex.*``)
 
 and ``summarize`` reduces such a dict, over a window ``[lo, hi]``, to the
 numbers the readers take. The tests run ``summarize`` on a small recorded
@@ -14,7 +16,7 @@ from __future__ import annotations
 
 import re
 
-SPAN_PREFIX = "bench."
+SPAN_PREFIX = ("bench.", "fmplex.")
 WINDOW_SPAN = "bench.trace_window"
 # lines of a TPU plane that hold one event per executed operation
 OP_LINES = ("XLA Ops",)
@@ -111,7 +113,7 @@ def summarize(events: dict, lo: float, hi: float, *,
               top: int = 10) -> dict:
     """Over ``[lo, hi]`` (ns): per chip the busy time (union of operation
     intervals), the time per operation name, and the idle gaps, each named
-    by the innermost benchmark span open on the host at its midpoint
+    by the innermost host span open at its midpoint
     ("host.outside_spans" where none was). Seconds throughout."""
     chips = {}
     spans = [(n, s, s + d) for n, s, d in events["host"]

@@ -1,5 +1,7 @@
-"""Seeded random weights for a configuration, made on the device in one
-jitted call, in float32 (the type the program keeps its parameters in).
+"""Seeded random weights for a dense transformer configuration, made on the
+device in one jitted call, in float32 (the type the program keeps its
+parameters in): the ``weights`` module of such a configuration
+(``bench/spec.py`` gives the contract).
 
 The benchmark makes the weights itself, and the reference makes them again
 from the same seed after the program is gone: the reference takes nothing
